@@ -1,11 +1,7 @@
 """Repo-level pytest bootstrap.
 
-* Puts ``src/`` on ``sys.path`` so a fresh checkout can run plain
-  ``pytest`` (the tier-1 command's ``PYTHONPATH=src`` stays supported and
-  equivalent).
-* Installs the dependency-free ``repro.testing.minihypothesis`` shim when
-  the optional ``hypothesis`` dev dependency is missing, so property tests
-  still collect and run (with fewer, deterministic examples).
+Puts ``src/`` on ``sys.path`` so a fresh checkout can run plain ``pytest``
+(the tier-1 command's ``PYTHONPATH=src`` stays supported and equivalent).
 """
 import os
 import sys
@@ -13,10 +9,3 @@ import sys
 _SRC = os.path.join(os.path.dirname(__file__), "src")
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    from repro.testing import minihypothesis
-
-    minihypothesis.install()

@@ -23,7 +23,7 @@ N_REQ = 8
 
 
 def run(strategy: str, period_s: float):
-    controller, make_request = build_demo(ARCH, strategy=strategy)
+    controller, make_request = build_demo(ARCH, reduced=True, strategy=strategy)
     res = run_schedule(
         controller, (make_request() for _ in range(N_REQ)), period_s=period_s
     )

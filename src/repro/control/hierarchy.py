@@ -21,7 +21,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.phases import WorkloadItem
 from repro.fleet.router import ROUTER_CODES

@@ -42,7 +42,7 @@ from typing import Callable, Optional
 import numpy as np
 
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.fleet.state import FleetParams, FleetState
 from repro.fleet.step import PeriodicFleetResult, routed_ledger, run_periodic, run_routed

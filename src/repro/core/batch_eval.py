@@ -36,7 +36,7 @@ Bit-agreement contract
 The scalar path is the *reference oracle*: every quantity here is computed
 with the identical sequence of IEEE-754 double ops as its scalar counterpart
 (same association order, same :data:`~repro.core.energy_model.FLOOR_EPS`
-floor convention), under ``jax.experimental.enable_x64``.  By default the
+floor convention), under ``jax.enable_x64``.  By default the
 kernels run **eagerly** — op-by-op, each primitive correctly rounded — so
 ``n_max`` matches the scalar path *exactly* (integer equality) and
 energies/lifetimes match bit-for-bit.  Pass ``jit=True`` for XLA fusion
@@ -95,7 +95,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import energy_model as em
 from repro.core.config_phase import (

@@ -25,7 +25,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core.batch_eval import GridResult, config_phase_grid
 from repro.core.config_phase import FpgaDevice

@@ -33,7 +33,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.fleet.state import FleetParams, FleetState
 

@@ -2,7 +2,7 @@
 
 :func:`run_periodic_sharded` partitions the device axis of
 :func:`repro.fleet.step.run_periodic` over a 2-D ``("fleet", "seed")``
-mesh via :func:`repro.compat.shard_map` + the logical-axis rules of
+mesh via :func:`jax.shard_map` + the logical-axis rules of
 :mod:`repro.distributed.sharding`; :func:`run_periodic_ensemble_sharded`
 does the same for the Monte Carlo ensemble, sharding devices over the
 ``fleet`` axis and seeds over the ``seed`` axis.
@@ -64,10 +64,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.distributed import sharding as shd
 from repro.fleet.state import FleetParams
 from repro.fleet.step import (
@@ -252,7 +251,7 @@ def _sharded_chunk_fn(mesh: Mesh, n_chunk: int):
         # int32 partial sums + psum == the unsharded global sum, exactly
         return n2, a2, lax.psum(ts, MESH_AXES)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(pspec, pspec, pspec),
@@ -273,7 +272,7 @@ def _eager_chunk_fn(mesh: Mesh, n_chunk: int):
         )
         return n2, a2, lax.psum(ts, MESH_AXES)
 
-    return compat.shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(pspec, pspec, pspec),
@@ -375,7 +374,7 @@ def _sharded_ens_fn(mesh: Mesh):
 
         return _periodic_ens_vmapped(p, lim, gp, gn)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(dev, dev, gap, gap),

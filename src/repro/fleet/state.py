@@ -29,7 +29,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import energy_model as em
 from repro.core.adaptive import AdaptiveStrategy, break_even_timeout_ms

@@ -43,7 +43,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import energy_model as em
 from repro.fleet.router import ROUTER_CODES, route_counts

@@ -1,9 +1,17 @@
 """Pallas TPU kernel: blocked int8 → bf16 dequantize (weight-load path).
 
 Bring-up ("configuration phase") reads int8 weights + scales from HBM and
-writes bf16 — the kernel tiles (Br × Bc) blocks through VMEM so the
-dequant runs at HBM streaming bandwidth; column groups of 128 share one
-fp32 scale (lane-aligned broadcast).
+writes bf16 — the kernel tiles (Br × C) row blocks through VMEM so the
+dequant runs at HBM streaming bandwidth; column groups of ``group`` share
+one fp32 scale.
+
+Each block spans whole rows: the scales block is then ``(Br, C/group)``,
+the full scale width, which the TPU lowering accepts for any group count
+(a partial lane block must be a multiple of 128).  Every group is scaled
+by a static, group-aligned lane slice times one broadcast scale column, so
+no lane repeat is needed.  The row grid is ``cdiv(R, Br)``: a ragged last
+block (e.g. the 151,936-row qwen3 embedding) is padded on read and masked
+on write.
 """
 from __future__ import annotations
 
@@ -13,13 +21,22 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+#: Elements of q per row block — int8 in, bf16/f32 out, double-buffered,
+#: plus the f32 product stays well inside v5e's 16 MiB scoped VMEM.
+_BLOCK_ELEMS = 1 << 19
+
 
 def _dequant_kernel(q_ref, s_ref, o_ref, *, group: int):
-    q = q_ref[...].astype(jnp.float32)            # (Br, Bc)
-    s = s_ref[...]                                # (Br, Bc/group)
-    br, bc = q.shape
-    s_full = jnp.repeat(s, group, axis=1)         # (Br, Bc)
-    o_ref[...] = (q * s_full).astype(o_ref.dtype)
+    for k in range(q_ref.shape[1] // group):
+        cols = slice(k * group, (k + 1) * group)
+        q = q_ref[:, cols].astype(jnp.float32)            # (Br, group)
+        o_ref[:, cols] = (q * s_ref[:, k : k + 1]).astype(o_ref.dtype)
+
+
+def _block_rows(r: int, c: int, block_r: int | None) -> int:
+    if block_r is None:
+        block_r = max(32, (_BLOCK_ELEMS // c) // 32 * 32)
+    return min(block_r, r)
 
 
 def dequantize_blocked(
@@ -27,25 +44,23 @@ def dequantize_blocked(
     scales: jax.Array,     # fp32 (R, C/group)
     *,
     group: int = 128,
-    block_r: int = 256,
-    block_c: int = 512,
+    block_r: int | None = None,
     dtype=jnp.bfloat16,
     interpret: bool = False,
 ) -> jax.Array:
     r, c = q.shape
-    br = min(block_r, r)
-    bc = min(block_c, c)
-    assert r % br == 0 and c % bc == 0 and bc % group == 0, (r, c, br, bc)
+    assert c % group == 0 and scales.shape == (r, c // group), (q.shape, scales.shape, group)
+    br = _block_rows(r, c, block_r)
 
     kernel = functools.partial(_dequant_kernel, group=group)
     return pl.pallas_call(
         kernel,
-        grid=(r // br, c // bc),
+        grid=(pl.cdiv(r, br),),
         in_specs=[
-            pl.BlockSpec((br, bc), lambda i, j: (i, j)),
-            pl.BlockSpec((br, bc // group), lambda i, j: (i, j)),
+            pl.BlockSpec((br, c), lambda i: (i, 0)),
+            pl.BlockSpec((br, c // group), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((br, bc), lambda i, j: (i, j)),
+        out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), dtype),
         interpret=interpret,
     )(q, scales)

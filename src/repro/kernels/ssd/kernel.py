@@ -46,17 +46,22 @@ def _ssd_kernel(
     dsk = d_ref[...].astype(jnp.float32)         # (1, 1) scalar skip
 
     xbar = x * dt                                # dt-scaled input
-    la = a[0, 0] * dt[:, 0]                      # (Q,) log-decay per step
-    cs = jnp.cumsum(la)                          # (Q,)
-    total = cs[-1]
+    # within-chunk prefix sums without cumsum (the TPU lowering has none):
+    # a masked column sum — the product with a lower-triangular ones
+    # matrix — gives cs as a row, and a diagonal pick turns it into a column
+    rows = jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    tril = rows >= cols
+    la = jnp.broadcast_to(a * dt, (q, q))        # la[t, j] = log-decay of step t
+    cs_row = jnp.sum(jnp.where(rows <= cols, la, 0.0), axis=0, keepdims=True)  # (1, Q)
+    cs = jnp.sum(
+        jnp.where(rows == cols, jnp.broadcast_to(cs_row, (q, q)), 0.0),
+        axis=1, keepdims=True,
+    )                                            # (Q, 1), the same values
+    total = cs_row[:, q - 1 :]                   # (1, 1)
 
     # intra-chunk: L[i,j] = exp(cs_i − cs_j) for i ≥ j
-    li = cs[:, None] - cs[None, :]
-    tril = (
-        jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
-        >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    )
-    lmat = jnp.where(tril, jnp.exp(li), 0.0)
+    lmat = jnp.where(tril, jnp.exp(cs - cs_row), 0.0)
     scores = jax.lax.dot_general(
         cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                            # (Q, Q)
@@ -67,19 +72,19 @@ def _ssd_kernel(
 
     # inter-chunk: contribution of the entering state
     h = state_ref[...]                           # (P, N)
-    y += jnp.exp(cs)[:, None] * jax.lax.dot_general(
+    y += jnp.exp(cs) * jax.lax.dot_general(
         cm, h, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )
 
     # state update: H ← exp(total)·H + Σ_j exp(total − cs_j)·x̄_j ⊗ B_j
-    decay_to_end = jnp.exp(total - cs)           # (Q,)
+    decay_to_end = jnp.exp(total - cs)           # (Q, 1)
     s_c = jax.lax.dot_general(
-        xbar * decay_to_end[:, None], bm, (((0,), (0,)), ((), ())),
+        xbar * decay_to_end, bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )                                            # (P, N)
     state_ref[...] = jnp.exp(total) * h + s_c
 
-    y_ref[...] = (y + dsk[0, 0] * x).astype(y_ref.dtype)
+    y_ref[...] = (y + dsk * x).astype(y_ref.dtype)
 
     @pl.when(ic == n_chunks - 1)
     def _finish():

@@ -15,7 +15,6 @@ import datetime
 import json
 import os
 import platform
-import subprocess
 import sys
 import time
 
@@ -118,57 +117,86 @@ def finish_payload(payload: dict, elapsed_s: float, **meta) -> dict:
     return payload
 
 
+#: Root of the checkout this module lives in (``src/repro/launch/..``).
+REPO_ROOT = os.path.normpath(
+    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "..", "..")
+)
+
+
 def _git_sha() -> str | None:
-    """HEAD SHA of the repo this module lives in, or None outside git."""
+    """HEAD SHA of the checkout, read from ``.git`` without starting a
+    process; None outside a git checkout."""
+    git = os.path.join(REPO_ROOT, ".git")
     try:
-        out = subprocess.run(
-            ["git", "rev-parse", "HEAD"],
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-            capture_output=True, text=True, timeout=5,
-        )
-    except (OSError, subprocess.SubprocessError):
+        with open(os.path.join(git, "HEAD")) as f:
+            head = f.read().strip()
+        if not head.startswith("ref: "):
+            return head or None
+        ref = head[len("ref: "):]
+        if os.path.exists(os.path.join(git, ref)):
+            with open(os.path.join(git, ref)) as f:
+                return f.read().strip() or None
+        with open(os.path.join(git, "packed-refs")) as f:
+            for line in f:
+                sha, _, name = line.strip().partition(" ")
+                if name == ref:
+                    return sha
+    except OSError:
         return None
-    sha = out.stdout.strip()
-    return sha if out.returncode == 0 and sha else None
+    return None
+
+
+def device_info() -> dict:
+    """The devices JAX computes on, as JAX reports them: the platform and
+    ``device_kind`` of the first device, and how many there are."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
+#: The checkout's persistent compile cache, used when
+#: ``JAX_COMPILATION_CACHE_DIR`` is unset.  A fixed path: the path is part of
+#: the cache key, so a directory that moved between runs would never hit.
+COMPILE_CACHE_DIR = os.path.join(REPO_ROOT, ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.  ``JAX_COMPILATION_CACHE_DIR``, when set, is left
+    to JAX; otherwise the cache lives in ``.jax_cache/`` in the checkout."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
 
 
 def run_manifest(seed=None) -> dict:
     """Provenance block stamped into every emitted payload: git SHA,
-    interpreter/library versions, backend, seed, wall-clock.  Every field
-    degrades to None rather than raising — a manifest must never be the
-    reason a run fails."""
-    versions: dict[str, str | None] = {
-        "python": platform.python_version(),
-    }
-    backend = None
-    try:
-        import jax
+    interpreter/library versions, the device JAX ran on, seed, wall-clock.
+    The device fields are read from JAX, never guessed: a manifest that
+    cannot name its device raises."""
+    import jax
+    import jaxlib
+    import numpy
 
-        versions["jax"] = jax.__version__
-        try:
-            import jaxlib
-
-            versions["jaxlib"] = jaxlib.__version__
-        except Exception:
-            versions["jaxlib"] = None
-        try:
-            backend = jax.default_backend()
-        except Exception:
-            backend = None
-    except Exception:
-        versions["jax"] = None
-        versions["jaxlib"] = None
-    try:
-        import numpy
-
-        versions["numpy"] = numpy.__version__
-    except Exception:
-        versions["numpy"] = None
     now = datetime.datetime.now(datetime.timezone.utc)
     return {
         "git_sha": _git_sha(),
-        "versions": versions,
-        "backend": backend,
+        "versions": {
+            "python": platform.python_version(),
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "numpy": numpy.__version__,
+        },
+        "device": device_info(),
         "platform": platform.platform(),
         "seed": seed,
         "unix_time": round(now.timestamp(), 3),

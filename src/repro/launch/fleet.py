@@ -25,7 +25,12 @@ import math
 import sys
 import time
 
-from repro.launch._cli import emit, make_parser, powerup_overhead_mj
+from repro.launch._cli import (
+    emit,
+    enable_compile_cache,
+    make_parser,
+    powerup_overhead_mj,
+)
 
 
 def _build_params(args):
@@ -373,6 +378,7 @@ def main(argv=None) -> int:
     if args.dt_ms is None:
         args.dt_ms = args.period_ms
 
+    enable_compile_cache()
     import numpy as np
 
     from repro.fleet import fleet_summary, run_periodic, run_routed

@@ -1,7 +1,10 @@
 """Serving driver: duty-cycle strategy demo on a live engine.
 
-    python -m repro.launch.serve --arch qwen3-1.7b --reduced \
+    python -m repro.launch.serve --arch qwen3-1.7b \
         --period-ms 200 --requests 20 --strategy auto
+
+The model runs at its published widths; ``--reduced`` swaps in the small
+CPU-sized variant of the same architecture.
 """
 from __future__ import annotations
 
@@ -15,6 +18,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs import get_config
 from repro.core.duty_cycle import DutyCycleController, PowerModel
+from repro.launch._cli import enable_compile_cache
 from repro.serving.engine import ServingEngine, bring_up_from_checkpoint
 from repro.serving.scheduler import run_schedule
 from repro.models import model_zoo as zoo
@@ -22,7 +26,7 @@ from repro.models import model_zoo as zoo
 
 def build_demo(
     arch: str,
-    reduced: bool = True,
+    reduced: bool = False,
     max_len: int = 96,
     prompt_len: int = 32,
     batch: int = 2,
@@ -30,15 +34,16 @@ def build_demo(
     ckpt_dir: str | None = None,
     power: PowerModel | None = None,
     strategy: str = "auto",
+    seed: int = 0,
 ):
     cfg = get_config(arch, reduced=reduced)
     ckpt_dir = ckpt_dir or tempfile.mkdtemp(prefix="repro-serve-")
     manager = CheckpointManager(ckpt_dir, mode="zstd+int8")
     if not manager.steps():
-        params = zoo.init_params(cfg, jax.random.PRNGKey(0))
+        params = zoo.init_params(cfg, jax.random.PRNGKey(seed))
         manager.save(0, params)
 
-    rng = np.random.default_rng(0)
+    rng = np.random.default_rng(seed)
     def make_request():
         return {
             "tokens": jnp.asarray(
@@ -67,17 +72,21 @@ def build_demo(
     return controller, make_request
 
 
-def main() -> None:
+def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="small variant of the architecture (CPU runs)")
     ap.add_argument("--period-ms", type=float, default=300.0)
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--strategy", default="auto",
                     choices=["auto", "adaptive", "on_off", "idle_waiting"])
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    controller, make_request = build_demo(args.arch, strategy=args.strategy)
+    enable_compile_cache()
+    controller, make_request = build_demo(
+        args.arch, reduced=args.reduced, strategy=args.strategy
+    )
     result = run_schedule(
         controller,
         (make_request() for _ in range(args.requests)),

@@ -45,7 +45,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import energy_model as em
 from repro.core.arrivals import ArrivalProcess, bin_arrival_counts

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.distributed import sharding as shd
 from repro.models.common import Spec
@@ -181,7 +180,7 @@ def moe_block(
             f"{cfg.name}: no MoE sharding for E={e} on model={tp_size}"
         )
 
-    out = compat.shard_map(
+    out = jax.shard_map(
         impl,
         mesh=mesh,
         in_specs=(x_spec, P(), w_spec, w_spec, wd_spec),

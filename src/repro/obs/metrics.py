@@ -264,7 +264,7 @@ def scan_histogram(values, edges, mask=None):
     """
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         values = jnp.asarray(values, dtype=jnp.float64)

@@ -93,7 +93,8 @@ def render_markdown(report: Mapping) -> str:
     manifest = report.get("manifest")
     if manifest:
         sha = manifest.get("git_sha") or "?"
-        backend = manifest.get("backend") or "?"
+        device = manifest.get("device") or {}
+        backend = f"{device.get('platform', '?')} {device.get('kind', '?')} x{device.get('count', '?')}"
         versions = manifest.get("versions") or {}
         lines += [
             f"- git: `{sha[:12] if isinstance(sha, str) else sha}`"

@@ -25,7 +25,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import energy_model as em
 from repro.core.adaptive import break_even_timeout_ms

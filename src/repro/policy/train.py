@@ -32,7 +32,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 from jax.flatten_util import ravel_pytree
 
 from repro.core.arrivals import (

@@ -1,7 +1,1 @@
-"""Test-support utilities (not imported by library code).
-
-``minihypothesis`` is a dependency-free stand-in for the ``hypothesis``
-property-testing API surface this repo uses; ``conftest.py`` installs it
-only when the real package is missing so a clean container can still run
-the full suite.
-"""
+"""Test-support utilities (not imported by library code)."""

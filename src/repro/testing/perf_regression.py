@@ -115,7 +115,7 @@ def measure_scan_rate(n_steps: int = 200_000, reps: int = 3) -> float:
     f64 adds/selects per step) so it scales the same way across machines."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     with enable_x64():
         def body(carry, x):

@@ -14,7 +14,6 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.configs.perf import BASELINE, PerfConfig
 from repro.models import model_zoo as zoo
@@ -154,7 +153,7 @@ def make_train_step(
         rep_opt = jax.tree.map(lambda _: P(), state.opt)
         rep_err = jax.tree.map(lambda _: P(), state.compress_err)
         batch_spec = jax.tree.map(lambda _: P("pod"), batch)
-        new_p, new_opt, new_err, metrics = compat.shard_map(
+        new_p, new_opt, new_err, metrics = jax.shard_map(
             partial(pod_body),
             mesh=mesh,
             in_specs=(rep, rep_opt, rep_err, batch_spec, P()),

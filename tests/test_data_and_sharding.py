@@ -4,7 +4,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import get_config
 from repro.data.pipeline import SyntheticLMStream, TimeSeriesStream, batch_for_arch
 from repro.distributed import sharding as shd
@@ -57,7 +56,7 @@ class TestTimeSeries:
 class TestLogicalSharding:
     def setup_method(self):
         # abstract 16×16 production mesh: no devices needed for spec logic
-        self.mesh = compat.abstract_mesh((16, 16), ("data", "model"))
+        self.mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
     def test_divisibility_filtering(self):
         # vocab 504 on a 16-wide model axis must drop to None
@@ -92,13 +91,10 @@ class TestLogicalSharding:
     def test_tuple_rule_prefix(self):
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 devices")
-        kwargs = {}
-        if hasattr(jax.sharding, "AxisType"):
-            kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * 3
         mesh = jax.make_mesh(
             (2, 2, 1), ("pod", "data", "model"),
             devices=np.array(jax.devices() * 4)[:4].reshape(2, 2, 1),
-            **kwargs,
+            axis_types=(jax.sharding.AxisType.Auto,) * 3,
         )
 
 
@@ -121,19 +117,19 @@ class TestAxisSizeRequiresMesh:
             shd.divisible(12, "vocab")
 
     def test_explicit_mesh_still_works(self):
-        mesh = compat.abstract_mesh((4, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
         assert shd.axis_size("embed", mesh) == 4
         assert shd.divisible(12, "embed", mesh)
         assert not shd.divisible(13, "embed", mesh)
 
     def test_installed_mesh_still_works(self):
-        mesh = compat.abstract_mesh((4, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
         with shd.use_sharding(mesh):
             assert shd.axis_size("vocab") == 2
             assert shd.divisible(10, "vocab")
 
     def test_unmapped_axis_with_mesh_is_one(self):
         # an axis with no rule shards nowhere: size 1, everything divides
-        mesh = compat.abstract_mesh((4, 2), ("data", "model"))
+        mesh = jax.sharding.AbstractMesh((4, 2), ("data", "model"))
         assert shd.axis_size("no_such_logical_axis", mesh) == 1
         assert shd.divisible(7, "no_such_logical_axis", mesh)

@@ -5,7 +5,6 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs import SHAPES_BY_NAME, get_config
 from repro.configs.perf import BASELINE, PerfConfig
 from repro.launch import dryrun_lib as dl
@@ -14,12 +13,12 @@ from repro.launch.roofline import RooflineTerms
 
 @pytest.fixture
 def single_mesh():
-    return compat.abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 @pytest.fixture
 def multi_mesh():
-    return compat.abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+    return jax.sharding.AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 class TestBatchPspecs:

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.fleet import INT32_STEP_LIMIT, fleet_mesh, run_periodic, uniform_fleet
 from repro.fleet.dtypes import (

@@ -36,7 +36,7 @@ def test_moe_ep_sharded_matches_reference():
         from repro.distributed import sharding as shd
         from repro.models import moe
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         # 16 experts → the true expert-parallel (all_to_all) path
         cfg = dataclasses.replace(
             get_config("qwen3-moe-235b-a22b", reduced=True),
@@ -69,7 +69,7 @@ def test_moe_ftp_sharded_matches_reference():
         from repro.distributed import sharding as shd
         from repro.models import moe
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         cfg = get_config("mixtral-8x7b", reduced=True)   # 4 experts top-2
         assert not moe.uses_ep(cfg)
         from repro.models.common import init_from_specs
@@ -113,9 +113,9 @@ def test_sharded_train_step_matches_single_device():
                 state, metrics = jax.jit(fns.train_step)(state, batch, 1e-3)
                 return float(metrics["loss"])
 
-        mesh1 = jax.make_mesh((1, 1), ("data", "model"),
+        mesh1 = jax.make_mesh((1, 1), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
                               devices=jax.devices()[:1])
-        mesh8 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh8 = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         l1 = loss_after_step(mesh1)
         l8 = loss_after_step(mesh8)
         print("LOSS", l1, l8)
@@ -138,7 +138,7 @@ def test_checkpoint_elastic_remesh():
         d = tempfile.mkdtemp()
         m = CheckpointManager(d, mode="zstd")
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with shd.use_sharding(mesh_a):
             ps_a = zoo.param_pspecs(cfg, mesh_a)
             sharded = jax.device_put(params, jax.tree.map(
@@ -146,7 +146,7 @@ def test_checkpoint_elastic_remesh():
                 is_leaf=lambda x: isinstance(x, P)))
         m.save(1, sharded)
 
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = jax.make_mesh((2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with shd.use_sharding(mesh_b):
             _, host = m.restore_latest(zoo.param_shapes(cfg))
             ps_b = zoo.param_pspecs(cfg, mesh_b)
@@ -191,7 +191,7 @@ def test_elastic_training_resume_across_mesh_change():
         params = zoo.init_params(cfg, jax.random.PRNGKey(0))
 
         # reference: 6 uninterrupted steps on the big mesh
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         with shd.use_sharding(mesh_a):
             fns = make_train_step(cfg, perf, mesh=mesh_a)
             s_ref = fns.init_state(params)
@@ -210,7 +210,7 @@ def test_elastic_training_resume_across_mesh_change():
 
         plan = plan_elastic_mesh(survivors=4, model_axis=2)
         assert plan.devices == 4
-        mesh_b = jax.make_mesh((plan.data, plan.model), ("data", "model"),
+        mesh_b = jax.make_mesh((plan.data, plan.model), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2,
                                devices=jax.devices()[:4])
         _, host = m.restore_latest(jax.eval_shape(lambda s: s, s1))
         s2 = jax.tree.map(jnp.asarray, host)
@@ -227,17 +227,16 @@ def test_grad_compression_close_to_exact():
     run_py("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from repro import compat
         from repro.optim import grad_compress as gc
 
-        mesh = jax.make_mesh((2, 4), ("pod", "data"))
+        mesh = jax.make_mesh((2, 4), ("pod", "data"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def body(g_loc, e_loc):
             out, new_e = gc.compress_psum({"g": g_loc}, gc.CompressState({"g": e_loc}), "pod")
             return out["g"], new_e.error["g"]
 
-        out, err = compat.shard_map(
+        out, err = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P("pod"), P("pod")),
             out_specs=(P("pod"), P("pod")),
@@ -245,7 +244,7 @@ def test_grad_compression_close_to_exact():
         )(g, jnp.zeros_like(g))
         # exact mean over pods of each shard's grads == its own value
         # (each pod holds a different shard half; compare vs exact psum)
-        exact = compat.shard_map(
+        exact = jax.shard_map(
             lambda x: jax.lax.pmean(x, "pod"), mesh=mesh,
             in_specs=P("pod"), out_specs=P("pod"), check_vma=False)(g)
         rel = float(jnp.max(jnp.abs(out - exact)) / jnp.max(jnp.abs(exact)))
@@ -275,7 +274,7 @@ def test_compressed_crosspod_train_step():
         }
 
         def run(compress):
-            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+            mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 3)
             perf = PerfConfig(grad_compress_pod=compress)
             with shd.use_sharding(mesh, perf_rules(perf)):
                 fns = make_train_step(cfg, perf, mesh=mesh)
@@ -304,7 +303,7 @@ def test_roofline_parser_counts_sharded_collectives():
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.launch.roofline import parse_hlo_costs
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = jax.make_mesh((4, 2), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2)
         L, B, D, F = 7, 32, 256, 512
         Ws = jax.ShapeDtypeStruct((L, D, F), jnp.float32)
         X = jax.ShapeDtypeStruct((B, D), jnp.float32)

@@ -13,6 +13,7 @@ import json
 import pathlib
 import sys
 
+import jax
 import numpy as np
 import pytest
 
@@ -567,7 +568,9 @@ class TestManifestAndReport:
         assert m["versions"]["python"]
         assert m["versions"]["jax"]
         assert m["versions"]["numpy"]
-        assert m["backend"]
+        assert m["device"]["platform"] == jax.devices()[0].platform
+        assert m["device"]["kind"] == jax.devices()[0].device_kind
+        assert m["device"]["count"] == len(jax.devices())
         assert m["unix_time"] > 0
         assert "T" in m["timestamp"]
 
