@@ -138,6 +138,10 @@ def perf_rules(perf: PerfConfig) -> dict:
             if isinstance(v, tuple) and "pod" in v:
                 slim = tuple(a for a in v if a != "pod")
                 rules[k] = slim if slim else None
+        # the loss's per-chunk logits stay replicated over "model": XLA's
+        # SPMD partitioner (jax 0.9) aborts on a (data, model) constraint
+        # inside the chunked-loss scan within the pod-manual shard_map
+        rules["act_vocab"] = None
     if perf.shard_long_cache_over_model:
         rules["long_cache_seq"] = "model"
     if perf.shard_cache_seq_over_model:

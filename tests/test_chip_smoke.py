@@ -27,12 +27,12 @@ def no_compile_cache(monkeypatch):
 
 
 def test_serving_phase_reduced(smoke):
-    out = smoke.serving_phase(reduced=True, n_requests=2)
-    assert out["on_off"]["configurations"] == 2
-    assert len(out["on_off"]["bring_up_s"]) == 2
+    out = smoke.serving_phase(reduced=True, n_requests=3)
+    assert out["on_off"]["configurations"] == 3
+    assert len(out["on_off"]["bring_up_s"]) == 3
     assert out["idle_waiting"]["configurations"] == 1
     assert out["resident_bytes"] > 0
-    assert len(out["tokens"]) == 2
+    assert len(out["tokens"]) == 3
     # the CPU runs the XLA attention, so the "kernel" is the reference itself
     assert not out["prefill_has_pallas_kernel"]
     assert out["prefill_vs_reference_rel_err"] == 0.0
