@@ -44,6 +44,13 @@ from repro.launch._cli import device_info, enable_compile_cache  # noqa: E402
 #: wrong kernel is off by O(1).
 PREFILL_REL_TOL = 5e-2
 
+#: The served model, at its published widths unless ``reduced``.
+ARCH = "qwen3-1.7b"
+
+#: Energy budget of each simulated device: On-Off devices exhaust it inside
+#: the 1024-step horizon, Idle-Waiting ones outlive it.
+BUDGET_J = 2.0
+
 
 class SmokeFailure(RuntimeError):
     """A phase produced a wrong or missing result."""
@@ -88,9 +95,7 @@ def _serve(controller, requests) -> tuple[list, dict]:
     return tokens, times
 
 
-def serving_phase(
-    arch: str = "qwen3-1.7b", reduced: bool = False, n_requests: int = 3, seed: int = 0
-) -> dict:
+def serving_phase(reduced: bool = False, n_requests: int = 3) -> dict:
     import jax
     import numpy as np
     from functools import partial
@@ -99,11 +104,11 @@ def serving_phase(
     from repro.launch.serve import build_demo
     from repro.models import model_zoo as zoo
 
-    out: dict = {"arch": arch, "reduced": reduced}
+    out: dict = {"arch": ARCH, "reduced": reduced}
     with tempfile.TemporaryDirectory(prefix="chip-smoke-ckpt-") as ckpt:
         t0 = time.perf_counter()
         on_off, make_request = build_demo(
-            arch, reduced=reduced, ckpt_dir=ckpt, strategy="on_off", seed=seed
+            ARCH, reduced=reduced, ckpt_dir=ckpt, strategy="on_off"
         )
         out["init_and_save_checkpoint_s"] = time.perf_counter() - t0
         out["checkpoint_bytes"] = sum(
@@ -117,7 +122,7 @@ def serving_phase(
         _check(on_off.handle is None, "on_off left the engine resident")
 
         idle, _ = build_demo(
-            arch, reduced=reduced, ckpt_dir=ckpt, strategy="idle_waiting", seed=seed
+            ARCH, reduced=reduced, ckpt_dir=ckpt, strategy="idle_waiting"
         )
         tok_idle, out["idle_waiting"] = _serve(idle, requests)
         _check(out["idle_waiting"]["configurations"] == 1, out["idle_waiting"])
@@ -185,7 +190,7 @@ def fleet_cli_phase(devices: int = 4096, horizon_s: float = 10.0) -> dict:
     }
 
 
-def _periodic_fleet(n_devices: int, budget_j: float):
+def _periodic_fleet(n_devices: int):
     """On-Off and Idle-Waiting devices alternating, the paper's LSTM item at
     a 40 ms period, with each strategy's scalar ``simulate()`` oracle."""
     from repro.core import energy_model as em
@@ -197,7 +202,7 @@ def _periodic_fleet(n_devices: int, budget_j: float):
 
     specs = [
         ExperimentSpec(
-            workload=WorkloadSpec(budget_j, 40.0), item=paper_lstm_item(),
+            workload=WorkloadSpec(BUDGET_J, 40.0), item=paper_lstm_item(),
             strategy_kind=strategy, method=IdlePowerMethod.METHOD1_2,
             powerup_overhead_mj=em.CALIBRATED_POWERUP_OVERHEAD_MJ,
         )
@@ -245,12 +250,11 @@ def _check_periodic(result, oracles, n_steps: int) -> dict:
     }
 
 
-def periodic_phase(n_devices: int = 1_000_000, n_steps: int = 1024, budget_j: float = 2.0) -> dict:
-    """``run_periodic`` at fleet scale.  With a 2 J budget the On-Off devices
-    exhaust it inside the horizon and the Idle-Waiting ones outlive it."""
+def periodic_phase(n_devices: int = 1_000_000, n_steps: int = 1024) -> dict:
+    """``run_periodic`` at fleet scale."""
     from repro.fleet import run_periodic
 
-    params, oracles = _periodic_fleet(n_devices, budget_j)
+    params, oracles = _periodic_fleet(n_devices)
     run_periodic(params, n_steps)                      # compile
     t0 = time.perf_counter()
     result = run_periodic(params, n_steps)
@@ -262,16 +266,14 @@ def periodic_phase(n_devices: int = 1_000_000, n_steps: int = 1024, budget_j: fl
 # ---------------------------------------------------------------------------
 # (c) four chips
 # ---------------------------------------------------------------------------
-def sharded_phase(
-    n_chips: int = 4, n_devices: int = 1_000_000, n_steps: int = 1024, budget_j: float = 2.0
-) -> dict:
+def sharded_phase(n_chips: int = 4, n_devices: int = 1_000_000, n_steps: int = 1024) -> dict:
     """``run_periodic_sharded`` over ``n_chips`` on the fleet axis against
     ``run_periodic`` on one chip: every field bit-identical."""
     import numpy as np
 
     from repro.fleet import fleet_mesh, run_periodic, run_periodic_sharded
 
-    params, oracles = _periodic_fleet(n_devices, budget_j)
+    params, oracles = _periodic_fleet(n_devices)
     mesh = fleet_mesh(n_chips, 1)
     single = run_periodic(params, n_steps)
     run_periodic_sharded(params, n_steps, mesh=mesh)   # compile

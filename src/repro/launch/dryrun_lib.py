@@ -140,7 +140,9 @@ def perf_rules(perf: PerfConfig) -> dict:
                 rules[k] = slim if slim else None
         # the loss's per-chunk logits stay replicated over "model": XLA's
         # SPMD partitioner (jax 0.9) aborts on a (data, model) constraint
-        # inside the chunked-loss scan within the pod-manual shard_map
+        # inside the chunked-loss scan within the pod-manual shard_map.
+        # test_multidevice::test_crosspod_vocab_sharded_logits_abort_the_partitioner
+        # reproduces it and fails once the rule can go.
         rules["act_vocab"] = None
     if perf.shard_long_cache_over_model:
         rules["long_cache_seq"] = "model"

@@ -34,16 +34,15 @@ def build_demo(
     ckpt_dir: str | None = None,
     power: PowerModel | None = None,
     strategy: str = "auto",
-    seed: int = 0,
 ):
     cfg = get_config(arch, reduced=reduced)
     ckpt_dir = ckpt_dir or tempfile.mkdtemp(prefix="repro-serve-")
     manager = CheckpointManager(ckpt_dir, mode="zstd+int8")
     if not manager.steps():
-        params = zoo.init_params(cfg, jax.random.PRNGKey(seed))
+        params = zoo.init_params(cfg, jax.random.PRNGKey(0))
         manager.save(0, params)
 
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(0)
     def make_request():
         return {
             "tokens": jnp.asarray(

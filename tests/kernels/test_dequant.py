@@ -28,6 +28,26 @@ def test_kernel_matches_reference(r, c, group, dtype):
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
 
+@pytest.mark.parametrize("c", [2048, 2560])  # last: a ragged column block
+def test_kernel_column_blocks_match_reference(c):
+    """Leaves too wide for whole-row blocks are tiled over columns too, in
+    multiples of 128 * group (here group 8, so 1024 columns)."""
+    w = jax.random.normal(jax.random.PRNGKey(1), (100, c))  # ragged rows too
+    q, s = quantize_blocked(w, group=8)
+    ref = dequantize_blocked_reference(q, s, group=8, dtype=jnp.bfloat16)
+    out = dequantize_blocked(q, s, group=8, interpret=True, block_r=32, block_c=1024)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+def test_block_shape_tiles_columns_only_when_rows_do_not_fit():
+    from repro.kernels.dequant.kernel import _block_shape
+
+    assert _block_shape(151936, 2048, 128, None, None) == (256, 2048)
+    assert _block_shape(5120, 151936, 128, None, None) == (32, 16384)
+    with pytest.raises(AssertionError, match="multiple of 128"):
+        _block_shape(64, 4096, 8, None, 1000)
+
+
 @settings(max_examples=15, deadline=None)
 @given(
     r=st.sampled_from([32, 64]),

@@ -271,6 +271,34 @@ class TestSampleBatch:
             jax.random.PRNGKey(2), 16, 1000.0, include_origin=False))
         assert not np.any(t[:, 0] == 0.0)
 
+    @pytest.mark.parametrize("ndim", [1, 2])
+    @pytest.mark.parametrize(
+        "lengths",
+        [range(40), (255, 256, 257), (4095, 4096, 4097), (70000,)],
+        ids=["0-39", "255-257", "4095-4097", "70000"],
+    )
+    def test_prefix_sum_bit_identical_to_cumsum(self, lengths, ndim):
+        """``sample_batch``'s arrival times are ``prefix_sum`` of the gaps;
+        on the CPU they must equal ``jnp.cumsum``'s bit for bit, so the
+        sampled streams do not depend on which of the two computes them."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.arrivals import _prefix_sum_jit
+
+        rng = np.random.default_rng(len(lengths))
+        with jax.enable_x64():
+            for n in lengths:
+                shape = (n,) if ndim == 1 else (3, n)
+                # gaps spanning six decades, so any other association of
+                # the additions rounds differently
+                gaps = rng.exponential(40.0, shape) * 10.0 ** rng.uniform(-3, 3, shape)
+                x = jnp.asarray(gaps, jnp.float64)
+                np.testing.assert_array_equal(
+                    np.asarray(_prefix_sum_jit(x)), np.asarray(jnp.cumsum(x, axis=-1)),
+                    err_msg=f"shape {shape}",
+                )
+
     def test_invalid_args_rejected(self):
         import jax
 

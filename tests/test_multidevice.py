@@ -14,14 +14,18 @@ import pytest
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 
-def run_py(code: str, timeout=560) -> str:
+def _spawn(code: str, timeout: float) -> subprocess.CompletedProcess:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
-    out = subprocess.run(
+    return subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=timeout, env=env,
     )
+
+
+def run_py(code: str, timeout=560) -> str:
+    out = _spawn(code, timeout)
     assert out.returncode == 0, f"stdout:\n{out.stdout}\nstderr:\n{out.stderr[-3000:]}"
     return out.stdout
 
@@ -294,6 +298,44 @@ def test_compressed_crosspod_train_step():
         assert abs(l_c[-1] - l_ref[-1]) < 0.05         # compressed ≈ exact
         assert st.compress_err is not None
     """)
+
+
+def test_crosspod_vocab_sharded_logits_abort_the_partitioner():
+    """Why ``perf_rules`` leaves ``act_vocab`` unsharded under
+    ``grad_compress_pod``: with the loss logits constrained to
+    ``(data, None, model)`` inside the chunked-loss scan of the pod-manual
+    ``shard_map``, XLA's SPMD partitioner fails a check and aborts the
+    process.  Once this test fails, the compile goes through: delete the
+    ``act_vocab`` rule in ``perf_rules`` and this test."""
+    out = _spawn("""
+        import jax, jax.numpy as jnp
+        from repro.configs import get_config
+        from repro.configs.perf import PerfConfig
+        from repro.distributed import sharding as shd
+        from repro.launch.dryrun_lib import perf_rules
+        from repro.models import model_zoo as zoo
+        from repro.training.train_loop import make_train_step
+
+        cfg = get_config("yi-6b", reduced=True)
+        params = zoo.init_params(cfg, jax.random.PRNGKey(0), jnp.float32)
+        batch = {"tokens": jnp.zeros((8, 32), jnp.int32),
+                 "labels": jnp.zeros((8, 32), jnp.int32)}
+        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 3)
+        perf = PerfConfig(grad_compress_pod=True)
+        rules = perf_rules(perf)
+        assert rules["act_vocab"] is None
+        rules["act_vocab"] = "model"
+        with shd.use_sharding(mesh, rules):
+            fns = make_train_step(cfg, perf, mesh=mesh)
+            state = fns.init_state(params)
+            jax.jit(fns.train_step).lower(state, batch, 1e-2).compile()
+        print("COMPILED")
+    """, timeout=300)
+    assert out.returncode != 0 and "COMPILED" not in out.stdout, (
+        "the vocab-sharded logits now compile: drop perf_rules' act_vocab rule"
+    )
+    assert "spmd_partitioner_util" in out.stderr, out.stderr[-3000:]
 
 
 def test_roofline_parser_counts_sharded_collectives():

@@ -67,6 +67,7 @@ def test_flash_attention_qwen3_widths(one_chip, s):
     [
         (151936, 2048),      # qwen3-1.7b tied embedding (rows not a block multiple)
         (28 * 2048, 6144),   # stacked w_gate / w_up, reshaped as the serializer does
+        (5120, 151936),      # an untied (d_model, vocab) head: column blocks too
     ],
 )
 def test_dequant_qwen3_leaves(one_chip, rows, cols):
