@@ -18,6 +18,7 @@ import threading
 from typing import Any, Callable, Optional
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import serializer
 
@@ -57,8 +58,9 @@ class CheckpointManager:
         return path
 
     def restore(self, step: int, target: Any = None) -> Any:
-        with open(self._path(step), "rb") as f:
-            return serializer.deserialize(f.read(), target)
+        with TraceAnnotation("checkpoint/read"), open(self._path(step), "rb") as f:
+            data = f.read()
+        return serializer.deserialize(data, target)
 
     def restore_latest(self, target: Any = None) -> tuple[Optional[int], Any]:
         steps = self.steps()

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import msgpack
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 try:  # optional: stdlib zlib is the fallback codec when zstandard is absent
     import zstandard
@@ -132,7 +133,8 @@ def deserialize(data: bytes, target: Any = None) -> Any:
     """bytes → pytree.  If ``target`` (a pytree of arrays/SDS with the same
     structure) is given, leaves are restored into its structure; else a flat
     {path: array} dict is returned."""
-    payload = msgpack.unpackb(data, raw=False)
+    with TraceAnnotation("checkpoint/unpack"):
+        payload = msgpack.unpackb(data, raw=False)
     mode = payload["mode"]
     # blobs predating the codec field were always zstd-compressed
     dctx = _decompressor(payload.get("codec", "zstd")) if mode != "none" else None
@@ -144,17 +146,24 @@ def deserialize(data: bytes, target: Any = None) -> Any:
             qd = record["quant"]
             rows, group = qd["rows"], qd["group"]
             cols = int(np.prod(shape)) // rows
-            q = np.frombuffer(dctx.decompress(qd["q"]), np.int8).reshape(rows, cols)
-            scales = np.frombuffer(
-                dctx.decompress(qd["scales"]), np.float32
-            ).reshape(rows, cols // group)
-            mat = dq.dequantize(
-                jnp.asarray(q), jnp.asarray(scales), group=group,
-                dtype=jnp.dtype(dtype) if dtype != np.dtype("V2") else jnp.bfloat16,
-            )
-            arr = np.asarray(mat).reshape(shape)
+            with TraceAnnotation("checkpoint/decompress"):
+                q_raw = dctx.decompress(qd["q"])
+                scales_raw = dctx.decompress(qd["scales"])
+            q = np.frombuffer(q_raw, np.int8).reshape(rows, cols)
+            scales = np.frombuffer(scales_raw, np.float32).reshape(rows, cols // group)
+            with TraceAnnotation("checkpoint/dequant"):
+                mat = dq.dequantize(
+                    jnp.asarray(q), jnp.asarray(scales), group=group,
+                    dtype=jnp.dtype(dtype) if dtype != np.dtype("V2") else jnp.bfloat16,
+                )
+            # waits for the dequant kernel, then copies to the host
+            with TraceAnnotation("checkpoint/to_host"):
+                arr = np.asarray(mat).reshape(shape)
+        elif mode == "none":
+            arr = np.frombuffer(record["data"], dtype=dtype).reshape(shape)
         else:
-            raw = record["data"] if mode == "none" else dctx.decompress(record["data"])
+            with TraceAnnotation("checkpoint/decompress"):
+                raw = dctx.decompress(record["data"])
             arr = np.frombuffer(raw, dtype=dtype).reshape(shape)
         by_path[record["path"]] = arr
     if target is None:

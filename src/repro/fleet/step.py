@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax import enable_x64
+from jax.profiler import TraceAnnotation
 
 from repro.core import energy_model as em
 from repro.fleet.router import ROUTER_CODES, route_counts
@@ -203,15 +204,17 @@ def run_periodic(params: FleetParams, n_steps: int, jit: bool = True) -> Periodi
         fn = _periodic_scan_jit if jit else _periodic_scan
         n, alive, alive_ts = fn(params, n_steps)
         energy, lifetime = _periodic_final(params, n)
-    return PeriodicFleetResult(
-        params=params,
-        n_steps=n_steps,
-        n_items=np.asarray(n).astype(np.int64),
-        energy_mj=np.asarray(energy),
-        lifetime_ms=np.asarray(lifetime),
-        alive=np.asarray(alive),
-        alive_over_time=np.asarray(alive_ts),
-    )
+    # the first conversion waits for the scan
+    with TraceAnnotation("fleet/to_host"):
+        return PeriodicFleetResult(
+            params=params,
+            n_steps=n_steps,
+            n_items=np.asarray(n).astype(np.int64),
+            energy_mj=np.asarray(energy),
+            lifetime_ms=np.asarray(lifetime),
+            alive=np.asarray(alive),
+            alive_over_time=np.asarray(alive_ts),
+        )
 
 
 # ---------------------------------------------------------------------------

@@ -10,11 +10,12 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from functools import partial
+from functools import partial, update_wrapper
 from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ArchConfig
@@ -40,7 +41,6 @@ class ServingEngine:
         params: Any,
         max_len: int,
         perf: PerfConfig = BASELINE,
-        metrics: Optional[Any] = None,
     ):
         if not cfg.decode_supported:
             raise ValueError(f"{cfg.name} is encoder-only")
@@ -48,14 +48,14 @@ class ServingEngine:
         self.params = params
         self.max_len = max_len
         self.perf = perf
-        # optional repro.obs.metrics.MetricsRegistry — when present, the
-        # engine records generate/release/bring-up counters and latency
-        # histograms; None keeps the hot path untouched
-        self.metrics = metrics
-        self._prefill = jax.jit(
-            partial(zoo.prefill_fn, cfg=cfg, max_len=max_len, perf=perf)
-        )
-        self._decode = jax.jit(partial(zoo.decode_fn, cfg=cfg, perf=perf))
+        # wrapped so that the device trace names the programs
+        # jit_prefill_fn and jit_decode_fn
+        self._prefill = jax.jit(update_wrapper(
+            partial(zoo.prefill_fn, cfg=cfg, max_len=max_len, perf=perf), zoo.prefill_fn
+        ))
+        self._decode = jax.jit(update_wrapper(
+            partial(zoo.decode_fn, cfg=cfg, perf=perf), zoo.decode_fn
+        ))
 
     def generate(
         self, batch: dict, n_new: int, greedy: bool = True,
@@ -66,39 +66,27 @@ class ServingEngine:
                 "engine was released (powered off); bring up from a "
                 "checkpoint before generating"
             )
-        t0 = time.perf_counter()
-        logits, state = self._prefill(self.params, batch)
-        logits.block_until_ready()
-        t1 = time.perf_counter()
-        outs = []
-        tok = jnp.argmax(logits, -1).astype(jnp.int32)
-        for i in range(n_new):
-            outs.append(tok)
-            logits, state = self._decode(self.params, state, tok)
-            if greedy or key is None:
-                tok = jnp.argmax(logits, -1).astype(jnp.int32)
-            else:
-                key, sub = jax.random.split(key)
-                tok = jax.random.categorical(sub, logits).astype(jnp.int32)
-        jax.block_until_ready(outs[-1])
-        t2 = time.perf_counter()
-        result = GenerationResult(
-            tokens=jnp.stack(outs, axis=1), prefill_s=t1 - t0, decode_s=t2 - t1
-        )
-        if self.metrics is not None:
-            n_batch = int(result.tokens.shape[0])
-            self.metrics.counter("engine_generate_calls").inc()
-            self.metrics.counter("engine_tokens_generated").inc(n_batch * n_new)
-            from repro.obs.metrics import default_latency_edges_ms
-
-            edges = default_latency_edges_ms()
-            self.metrics.histogram("engine_prefill_ms", edges).observe(
-                1000.0 * result.prefill_s
+        with TraceAnnotation("generate"):
+            t0 = time.perf_counter()
+            logits, state = self._prefill(self.params, batch)
+            logits.block_until_ready()
+            t1 = time.perf_counter()
+            outs = []
+            tok = jnp.argmax(logits, -1).astype(jnp.int32)
+            for i in range(n_new):
+                with TraceAnnotation("generate/decode_step"):
+                    outs.append(tok)
+                    logits, state = self._decode(self.params, state, tok)
+                    if greedy or key is None:
+                        tok = jnp.argmax(logits, -1).astype(jnp.int32)
+                    else:
+                        key, sub = jax.random.split(key)
+                        tok = jax.random.categorical(sub, logits).astype(jnp.int32)
+            jax.block_until_ready(outs[-1])
+            t2 = time.perf_counter()
+            return GenerationResult(
+                tokens=jnp.stack(outs, axis=1), prefill_s=t1 - t0, decode_s=t2 - t1
             )
-            self.metrics.histogram("engine_decode_ms", edges).observe(
-                1000.0 * result.decode_s
-            )
-        return result
 
     @property
     def resident(self) -> bool:
@@ -120,9 +108,6 @@ class ServingEngine:
             if hasattr(leaf, "delete"):
                 leaf.delete()
         self.params = None
-        if self.metrics is not None:
-            self.metrics.counter("engine_releases").inc()
-            self.metrics.gauge("engine_resident").set(0)
 
 
 def bring_up_from_checkpoint(
@@ -131,25 +116,18 @@ def bring_up_from_checkpoint(
     max_len: int,
     perf: PerfConfig = BASELINE,
     warmup_batch: Optional[dict] = None,
-    metrics: Optional[Any] = None,
 ) -> ServingEngine:
     """The 'configuration phase': restore (decompress) weights + build the
     engine (+ optional jit warm-up so infer latency excludes compile)."""
-    t0 = time.perf_counter()
-    target = zoo.param_shapes(cfg)
-    _, params = manager.restore_latest(target)
-    if params is None:
-        raise FileNotFoundError(f"no checkpoint in {manager.directory}")
-    params = jax.tree.map(jnp.asarray, params)
-    engine = ServingEngine(cfg, params, max_len, perf, metrics=metrics)
-    if warmup_batch is not None:
-        engine.generate(warmup_batch, n_new=1)
-    if metrics is not None:
-        metrics.counter("engine_bring_ups").inc()
-        metrics.gauge("engine_resident").set(1)
-        from repro.obs.metrics import default_latency_edges_ms
-
-        metrics.histogram("engine_bring_up_ms", default_latency_edges_ms()).observe(
-            1000.0 * (time.perf_counter() - t0)
-        )
-    return engine
+    with TraceAnnotation("bring_up"):
+        target = zoo.param_shapes(cfg)
+        _, params = manager.restore_latest(target)
+        if params is None:
+            raise FileNotFoundError(f"no checkpoint in {manager.directory}")
+        with TraceAnnotation("bring_up/to_device"):
+            params = jax.block_until_ready(jax.tree.map(jnp.asarray, params))
+        with TraceAnnotation("bring_up/warmup"):
+            engine = ServingEngine(cfg, params, max_len, perf)
+            if warmup_batch is not None:
+                engine.generate(warmup_batch, n_new=1)
+        return engine

@@ -21,6 +21,8 @@ import itertools
 import time
 from typing import Any, Callable, Iterable, Optional
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.arrivals import ArrivalProcess
 from repro.core.duty_cycle import DutyCycleController, PowerModel
 
@@ -54,14 +56,15 @@ def run_arrival_schedule(
         target = t_start + offset
         # sleep out the gap, waking at the policy's timeout so a live
         # engine actually releases mid-gap (ski-rental/adaptive release)
-        while True:
-            now = clock()
-            if now >= target:
-                break
-            t_rel = controller.next_release_time()
-            wake = min(target, t_rel) if (t_rel is not None and t_rel > now) else target
-            sleep(wake - now)
-            controller.maybe_release(clock())
+        with TraceAnnotation("schedule/wait_arrival"):
+            while True:
+                now = clock()
+                if now >= target:
+                    break
+                t_rel = controller.next_release_time()
+                wake = min(target, t_rel) if (t_rel is not None and t_rel > now) else target
+                sleep(wake - now)
+                controller.maybe_release(clock())
         controller.submit(x)
         n += 1
     wall = clock() - t_start
