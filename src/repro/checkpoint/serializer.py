@@ -14,6 +14,10 @@ paper's compression axis:
 The format is mesh-agnostic: plain host numpy per leaf, keyed by pytree
 path — restoring onto a different mesh/pod count (elastic re-mesh) is just
 ``device_put`` with the new sharding.
+
+``deserialize`` returns a quantized leaf where the dequant kernel wrote it,
+on the default device, and every other leaf as host numpy.  A ``zstd+int8``
+restore therefore holds the whole dequantized tree on that one device.
 """
 from __future__ import annotations
 
@@ -131,14 +135,15 @@ def serialize(tree: Any, mode: str = "zstd", level: int = 3) -> bytes:
 
 def deserialize(data: bytes, target: Any = None) -> Any:
     """bytes → pytree.  If ``target`` (a pytree of arrays/SDS with the same
-    structure) is given, leaves are restored into its structure; else a flat
-    {path: array} dict is returned."""
+    structure) is given, leaves are restored into its structure and cast to
+    its dtypes; else a flat {path: array} dict is returned.  Quantized leaves
+    are device arrays, the others host numpy."""
     with TraceAnnotation("checkpoint/unpack"):
         payload = msgpack.unpackb(data, raw=False)
     mode = payload["mode"]
     # blobs predating the codec field were always zstd-compressed
     dctx = _decompressor(payload.get("codec", "zstd")) if mode != "none" else None
-    by_path: dict[str, np.ndarray] = {}
+    by_path: dict[str, Any] = {}
     for record in payload["leaves"]:
         shape = tuple(record["shape"])
         dtype = np.dtype(record["dtype"])
@@ -156,9 +161,7 @@ def deserialize(data: bytes, target: Any = None) -> Any:
                     jnp.asarray(q), jnp.asarray(scales), group=group,
                     dtype=jnp.dtype(dtype) if dtype != np.dtype("V2") else jnp.bfloat16,
                 )
-            # waits for the dequant kernel, then copies to the host
-            with TraceAnnotation("checkpoint/to_host"):
-                arr = np.asarray(mat).reshape(shape)
+            arr = mat.reshape(shape)  # stays on the device
         elif mode == "none":
             arr = np.frombuffer(record["data"], dtype=dtype).reshape(shape)
         else:

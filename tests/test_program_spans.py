@@ -26,13 +26,15 @@ from repro.serving.engine import bring_up_from_checkpoint
 from repro.serving.scheduler import run_arrival_schedule
 
 SPANS = ("bring_up", "checkpoint/read", "checkpoint/unpack", "checkpoint/decompress",
-         "checkpoint/dequant", "checkpoint/to_host", "bring_up/to_device", "bring_up/warmup",
+         "checkpoint/dequant", "bring_up/to_device", "bring_up/warmup",
          "generate", "generate/decode_step", "schedule/wait_arrival", "fleet/to_host")
 N_NEW = 16
 
 
 def host_spans(logdir: str) -> list[tuple[str, int, int]]:
-    """``(name, start_ns, end_ns)`` of every host event named in SPANS."""
+    """``(name, start_ns, end_ns)`` of every host event named in SPANS, and
+    of any other ``checkpoint/`` or ``bring_up/`` span, so that a bring-up
+    phase the program no longer opens (``checkpoint/to_host``) would show."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"), recursive=True)
@@ -41,7 +43,8 @@ def host_spans(logdir: str) -> list[tuple[str, int, int]]:
         if plane.name == "/host:CPU":
             for line in plane.lines:
                 out += [(e.name, e.start_ns, e.start_ns + e.duration_ns)
-                        for e in line.events if e.name in SPANS]
+                        for e in line.events
+                        if e.name in SPANS or e.name.startswith(("checkpoint/", "bring_up/"))]
     return sorted(out, key=lambda s: s[1])
 
 
@@ -86,8 +89,9 @@ def test_every_span_is_recorded(recorded):
 
 def test_bring_up_holds_its_phases(recorded):
     """One bring-up: one read and unpack, one decompress per leaf, one
-    dequant and one pull to the host per quantized leaf, then the upload
-    and the warm-up, whose ``generate`` takes one decode step."""
+    dequant per quantized leaf (whose result stays on the device, so no
+    ``checkpoint/to_host``), then the upload and the warm-up, whose
+    ``generate`` takes one decode step."""
     spans, leaves = recorded[:2]
     (up,) = [s for s in spans if s[0] == "bring_up"]
     counts = Counter(n for n, _, _ in inside(spans, up))
@@ -95,10 +99,10 @@ def test_bring_up_holds_its_phases(recorded):
     assert 0 < n_quant < len(leaves)
     assert counts == {"bring_up": 1, "checkpoint/read": 1, "checkpoint/unpack": 1,
                       "checkpoint/decompress": len(leaves), "checkpoint/dequant": n_quant,
-                      "checkpoint/to_host": n_quant, "bring_up/to_device": 1,
+                      "bring_up/to_device": 1,
                       "bring_up/warmup": 1, "generate": 1, "generate/decode_step": 1}
     every = Counter(n for n, _, _ in spans)
-    assert every["checkpoint/to_host"] == n_quant      # none outside the bring-up
+    assert every["checkpoint/dequant"] == n_quant      # none outside the bring-up
     (warmup,) = [s for s in spans if s[0] == "bring_up/warmup"]
     assert [n for n, _, _ in inside(spans, warmup)] == [
         "bring_up/warmup", "generate", "generate/decode_step"]
